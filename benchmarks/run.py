@@ -21,6 +21,9 @@ def _emit(rows):
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import kernel_bench, paper_figures as pf
 
     t0 = time.time()

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import routing
+from repro.distributed.sharding import mem_mesh
 from repro.core.arena import ArenaBuilder
 from repro.core.engine import PulseEngine
 from repro.core.structures import btree, hash_table, linked_list, skiplist
@@ -80,7 +81,7 @@ def bench_compacted_routing(n=2048, B=512, k_local=4):
         ]
     ).astype(np.int32)
     ptr0, scr0 = it.init(jnp.asarray(q), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
 
     runs = {}
     for compact in (False, True):
@@ -619,7 +620,7 @@ def bench_chaos(
         head = linked_list.build_into(b, keys, keys * 2)
         inj = FaultInjector(plan) if plan is not None else None
         eng = PulseEngine(
-            b.finish(), mesh=jax.make_mesh((P,), ("mem",)), fault_injector=inj
+            b.finish(), mesh=mem_mesh(P), fault_injector=inj
         )
         ft = FaultToleranceConfig(store=ArenaStore(tmp))
         svc = PulseService(
@@ -796,7 +797,7 @@ def bench_reshard(
         return b.finish(), root
 
     def serve(arena, root, nshards, reshard_at=None):
-        mesh = jax.sharding.Mesh(np.array(jax.devices()[:nshards]), ("mem",))
+        mesh = mem_mesh(nshards)
         eng = PulseEngine(arena, mesh=mesh)
         svc = PulseService(
             eng,
@@ -934,6 +935,9 @@ def bench_reshard(
 
 
 def main(argv=None):
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--json",

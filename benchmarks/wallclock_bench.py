@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import routing
+from repro.distributed.sharding import mem_mesh
 from repro.core.arena import ArenaBuilder
 from repro.core.iterator import execute_batched
 from repro.core.structures import btree, hash_table, linked_list, skiplist
@@ -306,6 +307,9 @@ def bench_verify_specialization(mesh, *, small: bool, repeats: int):
 
 
 def main(argv=None):
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--json",
@@ -325,7 +329,7 @@ def main(argv=None):
     )
     args = ap.parse_args(argv)
 
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     assert jax.device_count() >= P, jax.devices()
     cfgs = build_configs(args.small)
     print(

@@ -23,11 +23,15 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.structures import btree  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 
 P = 8
 rng = np.random.default_rng(1)
-mesh = jax.make_mesh((P,), ("mem",))
+mesh = mem_mesh(P)
 print(f"mesh: {P} memory nodes")
 
 # time-ordered keys (the BTrDB shape), partitioned allocation

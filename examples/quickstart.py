@@ -14,6 +14,9 @@ import jax.numpy as jnp
 from repro.core import PulseEngine, STATUS_DONE, STATUS_MAXED
 from repro.core.iterator import execute_batched, resume
 from repro.core.structures import hash_table
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 rng = np.random.default_rng(0)
 

@@ -18,6 +18,9 @@ from repro.kernels.paged_attention.ops import paged_attention
 from repro.models.model_zoo import build_model
 from repro.serving.batching import ContinuousBatcher, Request
 from repro.serving.kv_cache import PagedKVCache
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 rng = np.random.default_rng(0)
 cfg = get_reduced_config("qwen3_4b")

@@ -24,6 +24,9 @@ from repro.core.engine import PulseEngine
 from repro.core.structures import btree, hash_table, linked_list, skiplist
 from repro.serving.admission import TraversalRequest
 from repro.serving.traversal_service import PulseService, StructureSpec
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 RNG = np.random.default_rng(0)
 N = 1024

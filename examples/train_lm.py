@@ -18,6 +18,9 @@ from repro.distributed.checkpoint import CheckpointManager
 from repro.models.model_zoo import build_model
 from repro.training import optimizer as opt_mod
 from repro.training.train_loop import TrainConfig, TrainLoop, init_state
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)

@@ -129,6 +129,11 @@ class ExecResult:
     # engine already swapped its own resident arena to this value; callers
     # holding the pre-call Arena object keep an intact snapshot.
     arena: Arena | None = None
+    # where the batch ran: "xla" (single-node device executor), "kernel"
+    # (pulse_chase), "distributed" (mesh supersteps), "cpu_node" (host
+    # traversal, offloaded=False), "commit_oracle" (one-shard writes on the
+    # host's sequential-commit executor) or "distributed_commit"
+    path: str = "xla"
 
 
 class PulseEngine:
@@ -214,9 +219,10 @@ class PulseEngine:
         ``backend`` selects the single-node executor: ``"xla"`` is the pure
         JAX while_loop oracle; ``"kernel"`` runs the pulse_chase Pallas
         kernel under the variable-depth wave scheduler (compiled on TPU, the
-        Pallas interpreter elsewhere), retiring finished lanes between depth
-        quanta.  On a mesh, ``backend="kernel"`` threads the distributed
-        local chase through the kernel's vectorized iterator body
+        Pallas interpreter on the CPU backend; any other backend raises),
+        retiring finished lanes between depth quanta.  On a mesh,
+        ``backend="kernel"`` threads the distributed local chase through
+        the kernel's vectorized iterator body
         (``local_backend="kernel"``), so the overlapped local step shares
         the accelerator's compiled logic end-to-end.
 
@@ -265,7 +271,10 @@ class PulseEngine:
                 max_iters=max_iters, cache_nodes=cache_nodes,
             )
             status = np.where(iters >= max_iters, 2, STATUS_DONE).astype(np.int32)
-            return ExecResult(ptr, scratch, status, np.asarray(iters), trace, False)
+            return ExecResult(
+                ptr, scratch, status, np.asarray(iters), trace, False,
+                path="cpu_node",
+            )
 
         if self.mesh is not None and self.arena.num_shards > 1:
             if replication is not None:
@@ -291,6 +300,7 @@ class PulseEngine:
                 status=rec[:, routing.F_STATUS],
                 iters=rec[:, routing.F_ITERS],
                 stats=stats,
+                path="distributed",
             )
 
         if backend == "kernel":
@@ -380,6 +390,7 @@ class PulseEngine:
                 compact=compact, schedule=schedule, fabric=fabric,
                 fault_injector=self.fault_injector,
             )
+            path = "distributed_commit"
         else:
             from repro.core import commit as commit_mod
 
@@ -388,6 +399,7 @@ class PulseEngine:
                 max_iters=max_iters, k_local=k_local, compact=compact,
                 fault_injector=self.fault_injector,
             )
+            path = "commit_oracle"
         self.arena = new_arena
         return ExecResult(
             ptr=rec[:, routing.F_PTR],
@@ -396,6 +408,7 @@ class PulseEngine:
             iters=rec[:, routing.F_ITERS],
             stats=stats,
             arena=new_arena,
+            path=path,
         )
 
     def _execute_kernel(
@@ -413,7 +426,7 @@ class PulseEngine:
         rounds up to the depth quantum), except for fault_fn-retired lanes,
         whose counts include the clamped loads executed before the
         quantum-granular check caught them.  Runs the compiled kernel on TPU
-        and the Pallas interpreter elsewhere.
+        and the Pallas interpreter on CPU; any other backend is an error.
         """
         from repro.core.arena import PERM_READ
         from repro.kernels.pulse_chase import ops as chase_ops
@@ -433,13 +446,20 @@ class PulseEngine:
             ok = perms[np.clip(shard, 0, perms.shape[0] - 1)] & PERM_READ
             return (p < 0) | (p >= cap) | (ok != PERM_READ)
 
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"the pulse_chase kernel compiles for TPU only (and runs in "
+                f"the Pallas interpreter on CPU); backend is {platform!r}"
+            )
         ptr, scratch, st, wstats = chase_ops.pulse_chase_waves(
             self.arena.data, ptr0, scratch0, np.zeros(B, np.int32),
             logic_fn=logic, max_steps=max_steps, fault_fn=fault_fn,
-            interpret=jax.default_backend() != "tpu",
+            interpret=platform == "cpu",
         )
         status = np.where(st == 1, STATUS_DONE, STATUS_MAXED).astype(np.int32)
         status = np.where(wstats.faulted, STATUS_FAULT, status)
         return ExecResult(
-            ptr, scratch, status, wstats.retire_step.astype(np.int32), wstats
+            ptr, scratch, status, wstats.retire_step.astype(np.int32), wstats,
+            path="kernel",
         )

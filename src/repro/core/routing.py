@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map, shard_map_unchecked
+from jax import shard_map
 from repro.core import translation
 from repro.core.arena import (
     H_BUMP,
@@ -1732,8 +1732,9 @@ def get_fused_runner(
             in_specs = (P(axis_name), P(axis_name), P(), P(), P(), P())
             out_specs = (P(axis_name), P(), P(), P(), P(), P(), P())
         fn = jax.jit(
-            shard_map_unchecked(
-                loop, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+            shard_map(
+                loop, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False,
             ),
             donate_argnums=(0,),
         )
@@ -2118,12 +2119,14 @@ def distributed_execute(
                 out_specs = (P(axis_name), P(), P(), P(), P())
             # ISA-VM iterators run a lax.while_loop per step (the bounded
             # bytecode interpreter), which shard_map's replication checker
-            # cannot analyze -- use the unchecked shim for those, exactly as
-            # the fused/pipelined loops always do; traced iterators keep the
-            # checked wrapper as a free structural safety net.
-            sm = shard_map_unchecked if _is_vm_backed(it) else shard_map
+            # cannot analyze -- the check is off for those, exactly as the
+            # fused/pipelined loops always run; traced iterators keep the
+            # check as a free structural safety net.
             _STEP_CACHE[key] = jax.jit(
-                sm(superstep, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+                shard_map(
+                    superstep, mesh=mesh, in_specs=in_specs,
+                    out_specs=out_specs, check_vma=not _is_vm_backed(it),
+                )
             )
         else:
             CACHE_STATS.hits += 1

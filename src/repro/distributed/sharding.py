@@ -26,7 +26,22 @@ import re
 import numpy as np
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def mem_mesh(num_shards: int | None = None, *, devices=None, axis_name: str = "mem") -> Mesh:
+    """The 1-D memory-node mesh the sharded arena is range-partitioned over.
+
+    Built from the first ``num_shards`` of ``devices`` (default: all of
+    ``jax.devices()``) with an ``Auto`` axis: the routing supersteps rely on
+    plain gathers and ``with_sharding_constraint`` over arena-sized arrays,
+    which an ``Explicit`` axis (``jax.make_mesh``'s default) rejects.
+    """
+    devs = list(jax.devices() if devices is None else devices)
+    n = len(devs) if num_shards is None else num_shards
+    if n > len(devs):
+        raise ValueError(f"a {n}-shard mesh needs {n} devices, have {len(devs)}")
+    return Mesh(np.array(devs[:n]), (axis_name,), axis_types=(AxisType.Auto,))
 
 
 def fsdp_axes(mesh: Mesh):

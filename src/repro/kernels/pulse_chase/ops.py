@@ -12,7 +12,8 @@ import jax.numpy as jnp
 
 from repro.core.iterator import PulseIterator
 from repro.core.routing import ExecutableCacheStats
-from repro.kernels.pulse_chase.kernel import pulse_chase_pallas
+from repro.kernels.pulse_chase.columns import lane_columns
+from repro.kernels.pulse_chase.kernel import LANES, pulse_chase_pallas
 from repro.kernels.pulse_chase.ref import chase_reference
 
 # Executable reuse accounting for the kernel backend (same discipline as the
@@ -24,7 +25,14 @@ CACHE_STATS = ExecutableCacheStats()
 
 def iterator_logic(it: PulseIterator):
     """Vectorized fused next+end body for a PulseIterator (the compiled
-    iterator the dispatch engine ships to the accelerator)."""
+    iterator the dispatch engine ships to the accelerator).
+
+    ``logic(nodes (B, W), ptr (B,), scratch (B, S)) -> (done, ptr,
+    scratch)`` is the ``vmap`` of the per-lane body, which XLA compiles (the
+    reference and the distributed local chase run it).  ``logic.columns`` is
+    the same body in lane-columnar form on ``(B, 1)`` pointer columns, the
+    form Mosaic compiles (``columns.lane_columns``): the kernel runs it, so
+    kernel-vs-reference tests check one form against the other."""
 
     def one(node, ptr, scratch):
         if it.step_fn is not None:
@@ -37,7 +45,52 @@ def iterator_logic(it: PulseIterator):
         done, nptr, nscr = jax.vmap(one)(nodes, ptr, scratch)
         return done, nptr.astype(jnp.int32), nscr.astype(jnp.int32)
 
+    logic.columns = lane_columns(one)
     return logic
+
+
+def check_lane_columns(it: PulseIterator, node_words: int) -> None:
+    """Trace ``it``'s body in lane-columnar form at the kernel's padded
+    record width; raises ``NotImplementedError`` naming what the compiled
+    kernel cannot run (``columns``)."""
+    wave, width = 8, -(-node_words // LANES) * LANES
+    jax.eval_shape(
+        iterator_logic(it).columns,
+        jax.ShapeDtypeStruct((wave, width), jnp.int32),
+        jax.ShapeDtypeStruct((wave, 1), jnp.int32),
+        jax.ShapeDtypeStruct((wave, it.scratch_words), jnp.int32),
+    )
+
+
+# the last lane-padded arena, as (source buffer, padded copy)
+_PADDED: tuple = (None, None)
+
+
+def lane_pad(arena_data: jax.Array) -> jax.Array:
+    """Zero-pad node records to a multiple of 128 int32 lanes: the compiled
+    kernel DMAs whole rows, and Mosaic slices HBM rows only in whole lane
+    tiles.  Padding words are never read by an iterator.
+
+    The padded copy of the last device arena is kept, keyed by the identity
+    of its (immutable) buffer: a resident arena is padded once, and a write
+    that swaps the arena's buffer is padded anew on its next read."""
+    global _PADDED
+    W = arena_data.shape[1]
+    if W % LANES == 0:
+        return arena_data
+    src, padded = _PADDED
+    if src is arena_data:
+        return padded
+    padded = _pad_lanes(arena_data, -(-W // LANES) * LANES)
+    if isinstance(arena_data, jax.Array) and not isinstance(arena_data, jax.core.Tracer):
+        _PADDED = (arena_data, padded)
+    return padded
+
+
+@partial(jax.jit, static_argnums=1)
+def _pad_lanes(arena_data: jax.Array, width: int) -> jax.Array:
+    pad = width - arena_data.shape[1]
+    return jnp.pad(jnp.asarray(arena_data, jnp.int32), ((0, 0), (0, pad)))
 
 
 @partial(
@@ -55,7 +108,7 @@ def _pulse_chase_donated(
     logic_fn,
     num_steps: int,
     wave: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
     use_pallas: bool = True,
 ):
     """The one compiled executable behind both entry points.
@@ -76,12 +129,12 @@ def _pulse_chase_donated(
             arena_data, ptr, scratch, status, iters, logic_fn, num_steps
         )
     return pulse_chase_pallas(
-        jnp.asarray(arena_data, jnp.int32),
+        arena_data,
         ptr,
         scratch,
         status,
         iters,
-        logic_fn=logic_fn,
+        columns_fn=logic_fn.columns,
         num_steps=num_steps,
         wave=wave,
         interpret=interpret,
@@ -98,7 +151,7 @@ def pulse_chase(
     logic_fn,
     num_steps: int,
     wave: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
     use_pallas: bool = True,
 ):
     """Run ``num_steps`` traversal iterations for a batch of lanes.
@@ -109,9 +162,10 @@ def pulse_chase(
     including the step that discovers done, matching the XLA executor's
     runnable-gated accounting bit-for-bit.
 
-    ``use_pallas=False`` falls back to the pure-jnp reference (the XLA path
-    models use on CPU); ``interpret=True`` runs the Pallas kernel body in
-    interpret mode (CPU validation of the TPU kernel).
+    ``use_pallas=False`` runs the pure-jnp reference instead of the kernel;
+    ``interpret=True`` runs the Pallas kernel body in interpret mode (CPU
+    validation of the TPU kernel).  The default compiles the kernel, which
+    needs a TPU.
 
     The caller's lane buffers are copied (``jnp.array``) before entering the
     donating executable, so they stay valid after the call.
@@ -119,7 +173,7 @@ def pulse_chase(
     if iters is None:
         iters = jnp.zeros(jnp.asarray(ptr).shape, jnp.int32)
     return _pulse_chase_donated(
-        arena_data,
+        lane_pad(arena_data) if use_pallas else arena_data,
         jnp.array(ptr, jnp.int32),
         jnp.array(scratch, jnp.int32),
         jnp.array(status, jnp.int32),
@@ -185,7 +239,7 @@ def pulse_chase_waves(
     max_steps: int,
     depth_quantum: int = 8,
     wave: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
     use_pallas: bool = True,
     fault_fn=None,
 ):
@@ -215,6 +269,8 @@ def pulse_chase_waves(
     Returns ``(ptr, scratch, status, stats)`` in the original lane order;
     results are identical to running the fixed scheduler for ``max_steps``.
     """
+    if use_pallas:
+        arena_data = lane_pad(arena_data)  # once, not per depth quantum
     out_ptr = np.asarray(ptr, np.int32).copy()
     out_scr = np.asarray(scratch, np.int32).copy()
     out_st = np.asarray(status, np.int32).copy()

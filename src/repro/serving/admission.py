@@ -321,7 +321,12 @@ class AdmissionController:
     def __len__(self) -> int:
         return self.pending()
 
-    def admit(self, free_slots: dict[str, int]) -> list[TraversalRequest]:
+    def admit(
+        self,
+        free_slots: dict[str, int],
+        write_key=None,
+        busy_keys=(),
+    ) -> list[TraversalRequest]:
         """Fill free slots from the queues; returns the admitted requests.
 
         Selection loop: among every tenant's head request whose structure
@@ -329,8 +334,16 @@ class AdmissionController:
         arrival) triple.  A head whose group is full blocks its tenant for
         this round (FIFO within tenant is preserved) -- the tenant's later
         requests for non-full groups wait their turn.
+
+        ``write_key(req)`` names the datum a write request mutates (None
+        for reads).  A head whose key is in ``busy_keys`` (writes still in
+        flight) or was admitted earlier in this round blocks its tenant the
+        same way: two writes to one datum never share a write batch, whose
+        commit phase would order them by slot rather than by arrival, so
+        they commit in admission order.
         """
         free = {k: int(v) for k, v in free_slots.items() if v > 0}
+        busy = set(busy_keys)
         admitted: list[TraversalRequest] = []
         while free:
             best_key = None
@@ -340,6 +353,8 @@ class AdmissionController:
                     continue
                 head = q[0]
                 if free.get(head.structure, 0) <= 0:
+                    continue
+                if busy and write_key is not None and write_key(head) in busy:
                     continue
                 deadline = (
                     float("inf")
@@ -358,5 +373,8 @@ class AdmissionController:
             free[req.structure] -= 1
             if free[req.structure] <= 0:
                 del free[req.structure]
+            wk = write_key(req) if write_key is not None else None
+            if wk is not None:
+                busy.add(wk)
             admitted.append(req)
         return admitted

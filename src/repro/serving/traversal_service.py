@@ -44,9 +44,7 @@ from collections import deque
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
 
 from repro.core import routing
 from repro.core.arena import NULL, remap_shards
@@ -61,6 +59,7 @@ from repro.core.iterator import (
     STATUS_SHED,
     PulseIterator,
 )
+from repro.distributed.sharding import mem_mesh
 from repro.serving.admission import (
     AdmissionController,
     TenantRateLimiter,
@@ -149,11 +148,19 @@ class ServiceMetrics:
     watchdog_suspects: int = 0
     reshards: int = 0
     reshard_drain_rounds: int = 0
+    # where quanta ran: ExecResult.path -> count
+    quanta_by_path: dict = dataclasses.field(default_factory=dict)
 
     def _pct(self, p: float) -> float:
         if not self.latencies_ms:
             return float("nan")
         return float(np.percentile(np.asarray(self.latencies_ms), p))
+
+    @property
+    def cpu_node_quanta(self) -> int:
+        """Quanta the dispatch cost model sent to the host traversal
+        (``cpu_node_execute``, ``ExecResult.offloaded=False``)."""
+        return self.quanta_by_path.get("cpu_node", 0)
 
     @property
     def p50_ms(self) -> float:
@@ -324,6 +331,26 @@ class PulseService:
         # to analyze and stay under the conservative runtime checks.
         for name, spec in structures.items():
             self._verify_spec(name, spec)
+        # the single-node kernel path runs a read iterator's body in the
+        # lane-columnar form Mosaic compiles; one it cannot express (the
+        # ISA VM's while loop over a program table) is refused here, before
+        # anything is enqueued, not when the first quantum traces
+        if backend == "kernel" and not (
+            engine.mesh is not None and engine.arena.num_shards > 1
+        ):
+            from repro.kernels.pulse_chase import ops as chase_ops
+
+            for name, spec in structures.items():
+                if not spec.writes:
+                    try:
+                        chase_ops.check_lane_columns(
+                            spec.iterator, engine.arena.node_words
+                        )
+                    except NotImplementedError as e:
+                        raise NotImplementedError(
+                            f"structure {name!r} cannot be served with "
+                            f"backend='kernel': {e}"
+                        ) from None
         self.groups = {
             name: _SlotGroup(name, spec, slots_per_structure)
             for name, spec in structures.items()
@@ -528,7 +555,20 @@ class PulseService:
             # reads must not block those reads (circular wait otherwise)
             self.admission.head_pending_by_structure(),
         )
-        admitted = self.admission.admit(free)
+        # one write per datum in flight: a writer whose key an occupied or
+        # just-admitted writer of its group holds waits for that commit
+        groups = self.groups
+
+        def write_key(r):
+            spec = groups[r.structure].spec
+            return (spec.group or r.structure, r.query) if spec.writes else None
+
+        busy = {
+            write_key(r)
+            for g in groups.values() if g.spec.writes
+            for r in g.req if r is not None
+        }
+        admitted = self.admission.admit(free, write_key, busy)
         by_group: dict[str, list[TraversalRequest]] = {}
         for r in admitted:
             by_group.setdefault(r.structure, []).append(r)
@@ -536,18 +576,22 @@ class PulseService:
             g = self.groups[name]
             fresh = [r for r in reqs if r.cont_ptr is None]
             if fresh:
-                queries = jnp.asarray(
-                    np.array([r.query for r in fresh], np.int32)
-                )
+                # init() runs eagerly: pad its batch to the group's slot
+                # count so every round reuses one compiled shape instead of
+                # compiling its ops for each new admitted-batch size
+                queries = np.zeros(g.n_slots, np.int32)
+                queries[: len(fresh)] = [r.query for r in fresh]
                 if g.spec.takes_value:
-                    values = jnp.asarray(
-                        np.array([r.value for r in fresh], np.int32)
-                    )
+                    values = np.zeros(g.n_slots, np.int32)
+                    values[: len(fresh)] = [r.value for r in fresh]
                     ptr0, scr0 = g.spec.iterator.init(
-                        queries, values, *g.spec.init_args
+                        jnp.asarray(queries), jnp.asarray(values),
+                        *g.spec.init_args,
                     )
                 else:
-                    ptr0, scr0 = g.spec.iterator.init(queries, *g.spec.init_args)
+                    ptr0, scr0 = g.spec.iterator.init(
+                        jnp.asarray(queries), *g.spec.init_args
+                    )
                 ptr0 = np.asarray(ptr0, np.int32)
                 scr0 = np.asarray(scr0, np.int32)
             free_idx = [i for i, r in enumerate(g.req) if r is None]
@@ -619,6 +663,7 @@ class PulseService:
         now_s = time.perf_counter()
         m = self.metrics
         m.engine_calls += 1
+        m.quanta_by_path[res.path] = m.quanta_by_path.get(res.path, 0) + 1
         g.fail_streak = 0  # a quantum landed: the group is healthy again
         stats = res.stats
         if stats is not None and hasattr(stats, "supersteps"):
@@ -904,13 +949,7 @@ class PulseService:
         new_arena = remap_shards(self.engine.arena, target)
         new_mesh = None
         if self.engine.mesh is not None:
-            devs = jax.devices()
-            if len(devs) < target:
-                raise RuntimeError(
-                    f"reshard to {target} shards needs {target} devices, "
-                    f"have {len(devs)}"
-                )
-            new_mesh = Mesh(np.array(devs[:target]), (self.engine.axis_name,))
+            new_mesh = mem_mesh(target, axis_name=self.engine.axis_name)
         ep = self._owner_map.advance(np.asarray(new_arena.bounds))
         old_epoch = ep.epoch - 1
 

@@ -10,6 +10,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.iterator import execute_batched  # noqa: E402
 from repro.core.structures import (  # noqa: E402
     bst,
@@ -35,7 +36,7 @@ def check_compact_equals_uncompacted():
         [RNG.integers(0, 8, B // 2), RNG.integers(n - 32, n, B // 2)]
     ).astype(np.int32)
     ptr0, scr0 = it.init(jnp.asarray(q), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
 
     o_ptr, o_scr, o_status, o_iters = execute_batched(it, ar, ptr0, scr0, max_iters=4096)
 
@@ -76,7 +77,7 @@ def check_compact_handles_faults():
     ptr0, scr0 = it.init(jnp.asarray(q), head)
     # corrupt half the start pointers -> switch-level fault
     ptr0 = jnp.asarray(np.where(np.arange(B) % 2 == 0, 10**6, np.asarray(ptr0)))
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     rec, st = routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=256, compact=True
     )
@@ -131,7 +132,7 @@ def check_fused_equivalence_all_structures():
     structure families -- including crossings and the schedule itself
     (supersteps / wire words / local-only counts), since the fused loop
     re-derives the exact same ladder decisions on-device."""
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for name, it, ar, p0, s0, max_iters in _five_structures():
         o_ptr, o_scr, o_status, o_iters = execute_batched(
             it, ar, p0, s0, max_iters=max_iters
@@ -169,7 +170,7 @@ def check_pipelined_equivalence_all_structures():
     hops/scratch), superstep counts, wire words, and local-only counts.  The
     pipelined loop re-derives the exact same ladder decisions from the same
     stale-by-one merged counts; overlap only reorders independent dataflow."""
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for name, it, ar, p0, s0, max_iters in _five_structures():
         o_ptr, o_scr, o_status, o_iters = execute_batched(
             it, ar, p0, s0, max_iters=max_iters
@@ -208,7 +209,7 @@ def check_pipelined_kernel_local_backend():
     """Threading the local chase through the pulse_chase kernel's vectorized
     iterator body must not change a bit (list exercises the next/end pair,
     btree the step_fn ISA path)."""
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for name, it, ar, p0, s0, max_iters in _five_structures()[:3]:
         rec_x, st_x = routing.distributed_execute(
             it, ar, p0, s0, mesh=mesh, max_iters=max_iters, compact=True,
@@ -233,7 +234,7 @@ def check_pipelined_handles_faults():
     q = keys[RNG.integers(0, n, B)].astype(np.int32)
     ptr0, scr0 = it.init(jnp.asarray(q), head)
     ptr0 = jnp.asarray(np.where(np.arange(B) % 2 == 0, 10**6, np.asarray(ptr0)))
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     rec_f, _ = routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=256, compact=True, schedule="fused"
     )
@@ -258,7 +259,7 @@ def check_fused_handles_faults():
     q = keys[RNG.integers(0, n, B)].astype(np.int32)
     ptr0, scr0 = it.init(jnp.asarray(q), head)
     ptr0 = jnp.asarray(np.where(np.arange(B) % 2 == 0, 10**6, np.asarray(ptr0)))
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     rec_d, _ = routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=256, compact=True, fused=False
     )

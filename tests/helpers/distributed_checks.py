@@ -11,6 +11,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.engine import PulseEngine  # noqa: E402
 from repro.core.iterator import STATUS_DONE, STATUS_FAULT, execute_batched  # noqa: E402
 from repro.core.structures import btree, hash_table, linked_list  # noqa: E402
@@ -20,7 +21,7 @@ P = 8
 
 
 def mesh():
-    return jax.make_mesh((P,), ("mem",))
+    return mem_mesh(P)
 
 
 def unique_keys(n, lo=0, hi=10**6):

@@ -29,6 +29,7 @@ import numpy as np
 import jax
 
 from repro.core.arena import ArenaBuilder, remap_shards
+from repro.distributed.sharding import mem_mesh
 from repro.core.engine import PulseEngine
 from repro.core.faults import FaultInjector, FaultPlan
 from repro.core.iterator import STATUS_DONE
@@ -71,7 +72,7 @@ def serve_rep(tmp, plan, pipeline, *, dead_rounds=3, watchdog=0.0,
               reads_only=False):
     arena, head = build_list()
     inj = FaultInjector(plan) if plan is not None else None
-    eng = PulseEngine(arena, mesh=jax.make_mesh((P,), ("mem",)),
+    eng = PulseEngine(arena, mesh=mem_mesh(P),
                       fault_injector=inj)
     ft = FaultToleranceConfig(
         store=ArenaStore(tmp), snapshot_every=100, dead_rounds=dead_rounds,
@@ -207,7 +208,7 @@ def bst_reqs(n=40, writes=True):
 
 def serve_reshard(arena, root, nshards, pipeline, *, reshard_at=None,
                   writes=True):
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:nshards]), ("mem",))
+    mesh = mem_mesh(nshards)
     eng = PulseEngine(arena, mesh=mesh)
     svc = PulseService(
         eng,

@@ -13,6 +13,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import commit, routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.arena import ArenaBuilder  # noqa: E402
 from repro.core.faults import FaultInjector, FaultPlan, ShardFailure  # noqa: E402
 from repro.core.iterator import STATUS_DONE  # noqa: E402
@@ -51,7 +52,7 @@ def check_kill_every_schedule():
     rec_o, st_o, ar_o = commit.sequential_commit_execute(
         it, arena, p0, s0, max_iters=4096
     )
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for schedule, fabric in SCHEDULES:
         inj = FaultInjector(FaultPlan(kill_shard=2, kill_superstep=3))
         try:
@@ -89,7 +90,7 @@ def check_kill_superstep_counting():
     arena, head, keys = _build()
     it = linked_list.find_iterator()
     p0, s0 = it.init(jnp.asarray(keys[:16]), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     rec_ref, st_ref = routing.distributed_execute(
         it, arena, p0, s0, mesh=mesh, max_iters=4096,
         compact=True, schedule="dispatched", fabric="dense",
@@ -126,7 +127,7 @@ def check_drop_retransmit_identity():
     it = linked_list.find_iterator()
     q = keys[RNG.permutation(len(keys))[:32]]
     p0, s0 = it.init(jnp.asarray(q), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for schedule, fabric in SCHEDULES:
         rec_ref, st_ref = routing.distributed_execute(
             it, arena, p0, s0, mesh=mesh, max_iters=4096,
@@ -170,7 +171,7 @@ def check_drop_write_path_validity():
     it = linked_list.insert_iterator()
     newk = (np.arange(12, dtype=np.int32) + 700)
     p0, s0 = it.init(jnp.asarray(newk), jnp.asarray(newk + 1), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for schedule, fabric in (("dispatched", "dense"), ("pipelined", "ring")):
         inj = FaultInjector(FaultPlan(drop_prob=0.3, drop_seed=3))
         rec, st, ar = routing.distributed_execute(
@@ -217,7 +218,7 @@ def check_delay_identity():
     arena, head, keys = _build()
     it = linked_list.find_iterator()
     p0, s0 = it.init(jnp.asarray(keys[:16]), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     rec_ref, st_ref = routing.distributed_execute(
         it, arena, p0, s0, mesh=mesh, max_iters=4096,
         compact=True, schedule="dispatched", fabric="dense",
@@ -248,7 +249,7 @@ def check_replica_fanout_matrix():
     it = linked_list.find_iterator()
     q = keys[RNG.permutation(len(keys))[:32]]
     p0, s0 = it.init(jnp.asarray(q), head)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     plan = routing.make_replica_plan(P, policy="failover")
     data = np.asarray(arena.data)
     bounds = np.asarray(arena.bounds)
@@ -323,7 +324,7 @@ def check_replication_service_matrix():
         head = linked_list.build_into(b, keys, keys * 2)
         inj = FaultInjector(plan) if plan is not None else None
         eng = PulseEngine(
-            b.finish(), mesh=jax.make_mesh((P,), ("mem",)), fault_injector=inj
+            b.finish(), mesh=mem_mesh(P), fault_injector=inj
         )
         ft = FaultToleranceConfig(
             store=ArenaStore(tmp), snapshot_every=100, dead_rounds=dead_rounds,

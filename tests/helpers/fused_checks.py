@@ -17,6 +17,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as Spec  # noqa: E402
 
 from repro.core import routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.engine import PulseEngine  # noqa: E402
 from repro.core.structures import btree, linked_list  # noqa: E402
 from repro.serving.admission import TraversalRequest  # noqa: E402
@@ -40,7 +41,7 @@ def check_repeated_execute_compiles_once():
     """Same-shaped fused executions after the first must be pure cache hits:
     zero traces, zero executable-cache misses."""
     it, ar, ptr0, scr0 = _list_setup()
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     eng = PulseEngine(ar, mesh=mesh)
     routing.reset_executable_caches()
     first = eng.execute(it, ptr0, scr0, max_iters=4096)
@@ -63,7 +64,7 @@ def check_service_quanta_compile_once():
     lkeys = np.arange(n, dtype=np.int32)
     lvals = RNG.integers(0, 10**6, n).astype(np.int32)
     ar, head = linked_list.build(lkeys, lvals, num_shards=P, policy="interleaved")
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     eng = PulseEngine(ar, mesh=mesh)
     svc = PulseService(
         eng,
@@ -97,7 +98,7 @@ def check_service_quanta_compile_once():
 def check_resident_arena_uploaded_once():
     """Consecutive executions reuse the device-resident arena buffers."""
     it, ar, ptr0, scr0 = _list_setup()
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     routing.reset_executable_caches()
     routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=4096, compact=True, fused=True
@@ -116,7 +117,7 @@ def check_donated_pool_consumed():
     handed -- and must not touch it afterwards (whitebox: call the cached
     executable directly with a pool we control)."""
     it, ar, ptr0, scr0 = _list_setup(B=16)
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     routing.reset_executable_caches()
     routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=4096, compact=True, fused=True
@@ -143,7 +144,7 @@ def check_pipelined_compiles_once_and_donates():
     arena survives.  Fused and pipelined runners coexist in the cache under
     distinct schedule keys."""
     it, ar, ptr0, scr0 = _list_setup()
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     routing.reset_executable_caches()
     first, _ = routing.distributed_execute(
         it, ar, ptr0, scr0, mesh=mesh, max_iters=4096, compact=True,
@@ -191,7 +192,7 @@ def check_pipelined_service_quanta_compile_once():
     lkeys = np.arange(n, dtype=np.int32)
     lvals = RNG.integers(0, 10**6, n).astype(np.int32)
     ar, head = linked_list.build(lkeys, lvals, num_shards=P, policy="interleaved")
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     eng = PulseEngine(ar, mesh=mesh)
     svc = PulseService(
         eng,

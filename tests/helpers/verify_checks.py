@@ -24,6 +24,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import isa, routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.commit import sequential_commit_execute  # noqa: E402
 from repro.core.iterator import execute_batched  # noqa: E402
 from repro.core.routing import F_ID, F_ITERS, F_PTR, F_SCRATCH, F_STATUS  # noqa: E402
@@ -37,7 +38,7 @@ PAYLOAD = [F_ID, F_PTR, F_STATUS, F_ITERS]
 
 
 def mesh():
-    return jax.make_mesh((P,), ("mem",))
+    return mem_mesh(P)
 
 
 def payload(rec, S):

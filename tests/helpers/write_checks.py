@@ -12,6 +12,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import commit, routing  # noqa: E402
+from repro.distributed.sharding import mem_mesh  # noqa: E402
 from repro.core.arena import PERM_READ, ArenaBuilder, make_arena  # noqa: E402
 from repro.core.iterator import STATUS_DONE, STATUS_FAULT  # noqa: E402
 from repro.core.structures import (  # noqa: E402
@@ -39,7 +40,7 @@ def _assert_matches_oracle(name, it, arena, p0, s0, *, max_iters):
     rec_o, st_o, ar_o = commit.sequential_commit_execute(
         it, arena, p0, s0, max_iters=max_iters
     )
-    mesh = jax.make_mesh((P,), ("mem",))
+    mesh = mem_mesh(P)
     for schedule, fabric in SCHEDULES:
         rec_d, st_d, ar_d = routing.distributed_execute(
             it, arena, p0, s0, mesh=mesh, max_iters=max_iters,
@@ -265,7 +266,7 @@ def check_service_async_sync_identity():
     def serve(pipeline):
         b = ArenaBuilder(512, 4, num_shards=P, policy="interleaved")
         head = linked_list.build_into(b, keys, keys * 2)
-        eng = PulseEngine(b.finish(), mesh=jax.make_mesh((P,), ("mem",)))
+        eng = PulseEngine(b.finish(), mesh=mem_mesh(P))
         svc = PulseService(
             eng,
             {
@@ -346,7 +347,7 @@ def check_service_chaos_recovery():
         head = linked_list.build_into(b, keys, keys * 2)
         inj = FaultInjector(plan) if plan is not None else None
         eng = PulseEngine(
-            b.finish(), mesh=jax.make_mesh((P,), ("mem",)), fault_injector=inj
+            b.finish(), mesh=mem_mesh(P), fault_injector=inj
         )
         # baseline-only snapshots (cadence larger than the workload): every
         # acknowledged write quantum sits in the commit log, so recovery

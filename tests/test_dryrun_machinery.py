@@ -58,10 +58,11 @@ def test_roofline_dominant_term():
 def test_step_builders_lower_on_tiny_mesh(arch):
     """build_step lowers (no compile) for each kind on a 1-device mesh with a
     tiny config -- catches spec/struct mismatches without the 512-dev cost."""
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.steps import build_step
 
     cfg = get_reduced_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh((1, 1), ("data", "model"))
     for shape_name in ("train_4k", "decode_32k"):
         shape = SHAPES[shape_name]
         small = type(shape)(shape.name, seq_len=64, global_batch=2, kind=shape.kind)

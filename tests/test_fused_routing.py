@@ -51,13 +51,13 @@ def test_kernel_wave_executables_reused_across_waves():
 
     first = ops.pulse_chase_waves(
         ar.data, ptr0, scr0, np.zeros(32, np.int32),
-        logic_fn=logic, max_steps=64, depth_quantum=8, wave=8,
+        logic_fn=logic, max_steps=64, depth_quantum=8, wave=8, interpret=True,
     )
     assert first[3].chunks > 1  # the schedule actually spans several waves
     ops.CACHE_STATS.reset()
     second = ops.pulse_chase_waves(
         ar.data, ptr0, scr0, np.zeros(32, np.int32),
-        logic_fn=logic, max_steps=64, depth_quantum=8, wave=8,
+        logic_fn=logic, max_steps=64, depth_quantum=8, wave=8, interpret=True,
     )
     assert ops.CACHE_STATS.traces == 0, ops.CACHE_STATS
     np.testing.assert_array_equal(first[0], second[0])
@@ -75,9 +75,13 @@ def test_pulse_chase_public_wrapper_preserves_caller_buffers():
     ptr0, scr0 = it.init(jnp.asarray(keys[:8]), head)
     st0 = jnp.zeros(8, jnp.int32)
     logic = ops.iterator_logic(it)
-    r1 = ops.pulse_chase(ar.data, ptr0, scr0, st0, logic_fn=logic, num_steps=40)
+    r1 = ops.pulse_chase(
+        ar.data, ptr0, scr0, st0, logic_fn=logic, num_steps=40, interpret=True
+    )
     assert not ptr0.is_deleted() and not scr0.is_deleted() and not st0.is_deleted()
-    r2 = ops.pulse_chase(ar.data, ptr0, scr0, st0, logic_fn=logic, num_steps=40)
+    r2 = ops.pulse_chase(
+        ar.data, ptr0, scr0, st0, logic_fn=logic, num_steps=40, interpret=True
+    )
     np.testing.assert_array_equal(np.asarray(r1[1]), np.asarray(r2[1]))
 
 

@@ -107,6 +107,42 @@ def test_pulse_chase_wave_iters_exact_vs_xla():
     np.testing.assert_array_equal(np.asarray(rk.iters), np.asarray(rx.iters))
 
 
+@pytest.mark.parametrize(
+    "name", ["hash", "btree", "btree_range", "list", "skiplist", "bst"]
+)
+def test_lane_columns_match_vmap(name):
+    """The lane-columnar body the compiled kernel runs is bit-identical to
+    the vmap of the per-lane iterator body, on arbitrary node contents (so
+    clamped dynamic indices, empty/overfull key counts and misses are all
+    exercised, not just well-formed nodes)."""
+    from repro.core.structures import bst, btree, hash_table, linked_list, skiplist
+    from repro.kernels.pulse_chase import ops
+
+    it = {
+        "hash": lambda: hash_table.find_iterator(64),
+        "btree": btree.find_iterator,
+        "btree_range": btree.range_aggregate_iterator,
+        "list": linked_list.find_iterator,
+        "skiplist": skiplist.find_iterator,
+        "bst": bst.find_iterator,
+    }[name]()
+    logic = ops.iterator_logic(it)
+    G, W, S = 64, 20, it.scratch_words
+    rng = np.random.default_rng(7)
+    nodes = rng.integers(-12, 12, (G, W)).astype(np.int32)
+    nodes[: G // 2] = rng.integers(-(2**31), 2**31 - 1, (G // 2, W), dtype=np.int64)
+    ptr = rng.integers(-1, 1000, G).astype(np.int32)
+    scr = rng.integers(-12, 12, (G, S)).astype(np.int32)
+    scr[:, 0] = nodes[np.arange(G), rng.integers(0, W, G)]  # some key hits
+    want = logic(jnp.asarray(nodes), jnp.asarray(ptr), jnp.asarray(scr))
+    got = jax.jit(logic.columns)(
+        jnp.asarray(nodes), jnp.asarray(ptr)[:, None], jnp.asarray(scr)
+    )
+    np.testing.assert_array_equal(np.asarray(got[0])[:, 0], np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1])[:, 0], np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+
+
 # --------------------------- flash_attention --------------------------------
 
 

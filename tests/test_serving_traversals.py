@@ -72,6 +72,21 @@ def test_admission_respects_per_structure_capacity():
     assert ac.pending() == 1  # the blocked head keeps its queue position
 
 
+def test_admission_serializes_writes_to_one_key():
+    """Two writes to one key never share a batch; a write whose key is in
+    flight waits, and blocks its tenant's later requests behind it."""
+    ac = AdmissionController()
+    for rid, (s, q) in enumerate([("w", 7), ("w", 8), ("w", 7), ("r", 7)]):
+        ac.submit(TraversalRequest(rid, s, q, tenant="a"), now_s=0.0)
+
+    def key(r):
+        return ("g", r.query) if r.structure == "w" else None
+
+    assert [r.req_id for r in ac.admit({"w": 4, "r": 4}, key)] == [0, 1]
+    assert ac.admit({"w": 4, "r": 4}, key, busy_keys={("g", 7)}) == []
+    assert [r.req_id for r in ac.admit({"w": 4, "r": 4}, key)] == [2, 3]
+
+
 # ----------------------------- service loop ----------------------------------
 
 
@@ -302,6 +317,22 @@ def test_service_tenant_fairness_under_flood():
     assert trickle_done < flood_done
 
 
+def test_service_refuses_kernel_backend_for_isa_iterators():
+    """The compiled kernel runs a read iterator's body in lane-columnar
+    form, which the ISA VM (a while loop over a program table) has not: the
+    service refuses such a structure on backend="kernel" at registration,
+    before anything is enqueued, and still accepts it on XLA."""
+    from repro.core import isa
+    from repro.core.structures import isa_programs
+
+    keys = np.arange(16, dtype=np.int32)
+    ar, head = linked_list.build(keys, keys + 100)
+    spec = {"lst": StructureSpec(isa.as_pulse_iterator(isa_programs.list_find_program()), (head,))}
+    with pytest.raises(NotImplementedError, match="'lst' cannot be served with backend='kernel'"):
+        PulseService(PulseEngine(ar), spec, backend="kernel")
+    assert "lst" in PulseService(PulseEngine(ar), spec).groups
+
+
 def test_service_kernel_backend_matches_xla():
     svc_x, data = _mixed_service(slots=4, quantum=16)
     svc_k, _ = _mixed_service(slots=4, quantum=16, backend="kernel")
@@ -358,7 +389,7 @@ def test_pulse_chase_waves_null_entry_retires_immediately():
     logic = ops.iterator_logic(it)
     p, s, st, stats = ops.pulse_chase_waves(
         ar.data, ptr0, scr0, jnp.zeros(8, jnp.int32),
-        logic_fn=logic, max_steps=32, wave=8,
+        logic_fn=logic, max_steps=32, wave=8, interpret=True,
     )
     assert (st == 1).all()
     assert (stats.retire_step[:4] == 0).all()  # never entered a chunk
@@ -456,3 +487,25 @@ def test_compacted_supersteps_subprocess():
     )
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
     assert "ALL COMPACTION CHECKS PASSED" in proc.stdout
+
+
+def test_service_counts_quanta_by_execution_path():
+    """Every quantum records where it ran; quanta the dispatch cost model
+    sends to the host traversal (cpu_node_execute) are counted, so a run
+    can prove none left the device path."""
+    keys = np.arange(64, dtype=np.int32)
+    ar, heads = hash_table.build(keys, keys * 5, 8)
+    spec = {"hash": StructureSpec(hash_table.find_iterator(8), (jnp.asarray(heads),))}
+    reqs = lambda: [TraversalRequest(i, "hash", query=int(k)) for i, k in enumerate(keys[:12])]
+
+    on_device = PulseService(PulseEngine(ar), spec, slots_per_structure=8)
+    m = on_device.run(reqs())
+    assert m.completed == 12 and m.cpu_node_quanta == 0
+    assert set(m.quanta_by_path) == {"xla"}
+    assert m.quanta_by_path["xla"] == m.engine_calls
+
+    # eta=0: no iterator fits under the offload threshold -> host traversal
+    on_host = PulseService(PulseEngine(ar, eta=0.0), spec, slots_per_structure=8)
+    m = on_host.run(reqs())
+    assert m.completed == 12
+    assert m.cpu_node_quanta == m.engine_calls == m.quanta_by_path["cpu_node"] > 0
