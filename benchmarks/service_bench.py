@@ -24,7 +24,7 @@ Four experiments:
   3. **PulseService mixed workload** -- a mixed 4-structure workload (list
      walk, B-tree lookup, hash-chain probe, skiplist search) from 3
      tenants served end-to-end through continuous batching; reports
-     p50/p99 latency, throughput, utilization, and per-tenant counts.
+     p50/p99 latency, throughput, and per-tenant counts.
 
   4. **LM batched prefill** -- the ContinuousBatcher's admission path:
      batched full-sequence prefill (one jitted call per admission) vs the
@@ -503,7 +503,6 @@ def bench_service(n_requests=600, slots=64, quantum=16, arrival=None, seed=42):
         "p50_ms": m.p50_ms,
         "p99_ms": m.p99_ms,
         "throughput_rps": m.throughput_rps,
-        "utilization": m.utilization,
         "arrival": arrival_info,
     }
 

@@ -37,6 +37,7 @@ from repro.core.iterator import (
     PulseIterator,
     execute_batched,
 )
+from repro.core.trace import TRACE
 
 # Re-exported: the specialization predicate lives with the distributed
 # executor but is part of the engine's public surface (callers asking "will
@@ -239,6 +240,35 @@ class PulseEngine:
         ``fused`` is the pre-pipelined boolean knob, still honored when
         ``schedule="auto"`` resolves away from it only by the overlap model.
         """
+        with TRACE.span("engine.execute", lanes=len(ptr0)) as span:
+            res = self._execute(
+                it, ptr0, scratch0, max_iters=max_iters,
+                force_offload=force_offload, return_to_cpu=return_to_cpu,
+                k_local=k_local, cache_nodes=cache_nodes, compact=compact,
+                fused=fused, backend=backend, schedule=schedule, fabric=fabric,
+                replication=replication,
+            )
+            span.set(path=res.path)
+        return res
+
+    def _execute(
+        self,
+        it: PulseIterator,
+        ptr0,
+        scratch0,
+        *,
+        max_iters: int,
+        force_offload: bool | None,
+        return_to_cpu: bool,
+        k_local: int,
+        cache_nodes: int,
+        compact: bool,
+        fused: bool,
+        backend: str,
+        schedule: str,
+        fabric: str,
+        replication: routing.ReplicaContext | None,
+    ) -> ExecResult:
         if it.mutates:
             # write iterators always run near-memory: the commit machinery
             # (per-shard serialization, free-list allocator) lives with the
@@ -262,7 +292,8 @@ class PulseEngine:
                 it, ptr0, scratch0, max_iters=max_iters, k_local=k_local,
                 compact=compact, fused=fused, schedule=schedule, fabric=fabric,
             )
-        decision = self.dispatch(it)
+        with TRACE.span("engine.dispatch"):
+            decision = self.dispatch(it)
         offload = decision.offload if force_offload is None else force_offload
         if not offload:
             self._local_fault_check()
@@ -317,26 +348,29 @@ class PulseEngine:
         # between calls) and key the cache on it: a revocation flips the key
         # back to the unspecialized executable instead of silently running
         # the probe-free one.
-        elide = routing.can_elide_access_check(it, self.arena)
-        ptr0 = jnp.array(ptr0, jnp.int32)
-        key = (it, int(ptr0.shape[0]), elide)
-        fn = self._local_jit.get(key)
-        if fn is None:
-            fn = jax.jit(
-                lambda arena, p, s, budget: execute_batched(
-                    it, arena, p, s, max_iters=budget, elide_access_check=elide
-                ),
-                donate_argnums=(1, 2),
+        with TRACE.span("engine.dispatch"):
+            elide = routing.can_elide_access_check(it, self.arena)
+        with TRACE.span("engine.launch"):
+            ptr0 = jnp.array(ptr0, jnp.int32)
+            key = (it, int(ptr0.shape[0]), elide)
+            fn = self._local_jit.get(key)
+            if fn is None:
+                def chase_local(arena, p, s, budget):
+                    return execute_batched(
+                        it, arena, p, s, max_iters=budget, elide_access_check=elide
+                    )
+                fn = jax.jit(chase_local, donate_argnums=(1, 2))
+                self._local_jit[key] = fn
+            ptr, scratch, status, iters = fn(
+                self.arena, ptr0, jnp.array(scratch0, jnp.int32),
+                jnp.int32(min(max_iters, (1 << 31) - 1)),
             )
-            self._local_jit[key] = fn
-        ptr, scratch, status, iters = fn(
-            self.arena, ptr0, jnp.array(scratch0, jnp.int32),
-            jnp.int32(min(max_iters, (1 << 31) - 1)),
-        )
-        return ExecResult(
-            np.asarray(ptr), np.asarray(scratch), np.asarray(status),
-            np.asarray(iters),
-        )
+        with TRACE.span("engine.d2h"):
+            TRACE.count("host_syncs", 4)
+            return ExecResult(
+                np.asarray(ptr), np.asarray(scratch), np.asarray(status),
+                np.asarray(iters),
+            )
 
     def _resolve_schedule(
         self, it: PulseIterator, schedule: str, fused: bool, k_local: int

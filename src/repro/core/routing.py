@@ -65,6 +65,7 @@ from repro.core.iterator import (
     mut_step_batch,
     step_batch,
 )
+from repro.core.trace import TRACE
 
 F_ID, F_HOME, F_PTR, F_STATUS, F_ITERS, F_HOPS, F_SCRATCH = 0, 1, 2, 3, 4, 5, 6
 
@@ -1761,6 +1762,7 @@ def can_elide_access_check(it: PulseIterator, arena: Arena) -> bool:
     facts = it.facts
     if facts is None or not getattr(facts, "read_only", False) or it.mutates:
         return False
+    TRACE.count("host_syncs")
     perms = np.asarray(arena.perms)
     return bool(np.all((perms & PERM_READ) == PERM_READ))
 

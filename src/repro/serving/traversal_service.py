@@ -59,6 +59,7 @@ from repro.core.iterator import (
     STATUS_SHED,
     PulseIterator,
 )
+from repro.core.trace import TRACE
 from repro.distributed.sharding import mem_mesh
 from repro.serving.admission import (
     AdmissionController,
@@ -114,8 +115,6 @@ class ServiceMetrics:
     timed_out: int = 0  # retired at max_request_iters
     wall_s: float = 0.0
     lane_iters: int = 0  # productive iterations executed
-    slot_rounds: int = 0  # occupied slot-rounds (for utilization)
-    capacity_rounds: int = 0  # total slot-rounds available
     latencies_ms: list = dataclasses.field(default_factory=list)
     per_tenant: dict = dataclasses.field(default_factory=dict)
     deadlines_met: int = 0
@@ -179,10 +178,6 @@ class ServiceMetrics:
         return self.completed / self.wall_s if self.wall_s else 0.0
 
     @property
-    def utilization(self) -> float:
-        return self.slot_rounds / self.capacity_rounds if self.capacity_rounds else 0.0
-
-    @property
     def mean_recovery_ms(self) -> float:
         if not self.recoveries:
             return float("nan")
@@ -199,8 +194,7 @@ class ServiceMetrics:
             f"faulted={self.faulted} "
             f"timed_out={self.timed_out} rounds={self.rounds} "
             f"p50={self.p50_ms:.2f}ms p99={self.p99_ms:.2f}ms "
-            f"throughput={self.throughput_rps:.0f} req/s "
-            f"util={self.utilization:.0%} shed={self.shed}"
+            f"throughput={self.throughput_rps:.0f} req/s shed={self.shed}"
         )
 
 
@@ -534,85 +528,88 @@ class PulseService:
         self.metrics.preempted += 1
 
     def _admit(self, now_s: float, rnd: int) -> None:
-        self._intake(now_s, rnd)
-        if self.preempt:
-            self._maybe_preempt(now_s)
-        free = {name: g.free_slots() for name, g in self.groups.items()}
-        # a group parked on a dead shard admits nobody until its backoff
-        # expires: the retried batch must re-run with its composition intact
-        # (identical batch -> identical allocation order -> bit-identical
-        # post-recovery arena)
-        for name, g in self.groups.items():
-            if g.backoff_until > rnd:
-                free[name] = 0
-        # write-path barrier: writers take their structure group exclusively
-        free = apply_write_barriers(
-            free,
-            {n: g.spec.group or n for n, g in self.groups.items()},
-            {n: g.spec.writes for n, g in self.groups.items()},
-            {n: bool(g.occupied().any()) for n, g in self.groups.items()},
-            # head-only pending: a writer buried behind its tenant's queued
-            # reads must not block those reads (circular wait otherwise)
-            self.admission.head_pending_by_structure(),
-        )
-        # one write per datum in flight: a writer whose key an occupied or
-        # just-admitted writer of its group holds waits for that commit
-        groups = self.groups
+        with TRACE.span("service.admit"):
+            self._intake(now_s, rnd)
+            if self.preempt:
+                self._maybe_preempt(now_s)
+            free = {name: g.free_slots() for name, g in self.groups.items()}
+            # a group parked on a dead shard admits nobody until its backoff
+            # expires: the retried batch must re-run with its composition intact
+            # (identical batch -> identical allocation order -> bit-identical
+            # post-recovery arena)
+            for name, g in self.groups.items():
+                if g.backoff_until > rnd:
+                    free[name] = 0
+            # write-path barrier: writers take their structure group exclusively
+            free = apply_write_barriers(
+                free,
+                {n: g.spec.group or n for n, g in self.groups.items()},
+                {n: g.spec.writes for n, g in self.groups.items()},
+                {n: bool(g.occupied().any()) for n, g in self.groups.items()},
+                # head-only pending: a writer buried behind its tenant's queued
+                # reads must not block those reads (circular wait otherwise)
+                self.admission.head_pending_by_structure(),
+            )
+            # one write per datum in flight: a writer whose key an occupied or
+            # just-admitted writer of its group holds waits for that commit
+            groups = self.groups
 
-        def write_key(r):
-            spec = groups[r.structure].spec
-            return (spec.group or r.structure, r.query) if spec.writes else None
+            def write_key(r):
+                spec = groups[r.structure].spec
+                return (spec.group or r.structure, r.query) if spec.writes else None
 
-        busy = {
-            write_key(r)
-            for g in groups.values() if g.spec.writes
-            for r in g.req if r is not None
-        }
-        admitted = self.admission.admit(free, write_key, busy)
-        by_group: dict[str, list[TraversalRequest]] = {}
-        for r in admitted:
-            by_group.setdefault(r.structure, []).append(r)
-        for name, reqs in by_group.items():
-            g = self.groups[name]
-            fresh = [r for r in reqs if r.cont_ptr is None]
-            if fresh:
-                # init() runs eagerly: pad its batch to the group's slot
-                # count so every round reuses one compiled shape instead of
-                # compiling its ops for each new admitted-batch size
-                queries = np.zeros(g.n_slots, np.int32)
-                queries[: len(fresh)] = [r.query for r in fresh]
-                if g.spec.takes_value:
-                    values = np.zeros(g.n_slots, np.int32)
-                    values[: len(fresh)] = [r.value for r in fresh]
-                    ptr0, scr0 = g.spec.iterator.init(
-                        jnp.asarray(queries), jnp.asarray(values),
-                        *g.spec.init_args,
-                    )
-                else:
-                    ptr0, scr0 = g.spec.iterator.init(
-                        jnp.asarray(queries), *g.spec.init_args
-                    )
-                ptr0 = np.asarray(ptr0, np.int32)
-                scr0 = np.asarray(scr0, np.int32)
-            free_idx = [i for i, r in enumerate(g.req) if r is None]
-            fi = 0
-            for j, r in enumerate(reqs):
-                s = free_idx[j]
-                g.req[s] = r
-                if r.cont_ptr is None:
-                    g.ptr[s] = ptr0[fi]
-                    g.scratch[s] = scr0[fi]
-                    g.iters[s] = 0
-                    fi += 1
-                else:  # preempted continuation: resume saved traversal state
-                    g.ptr[s] = r.cont_ptr
-                    g.scratch[s] = r.cont_scratch
-                    g.iters[s] = r.iters
-                    r.cont_ptr = None
-                    r.cont_scratch = None
-                if r.admit_s < 0:
-                    r.admit_s = now_s
-                    r.admit_round = rnd
+            busy = {
+                write_key(r)
+                for g in groups.values() if g.spec.writes
+                for r in g.req if r is not None
+            }
+            admitted = self.admission.admit(free, write_key, busy)
+            by_group: dict[str, list[TraversalRequest]] = {}
+            for r in admitted:
+                by_group.setdefault(r.structure, []).append(r)
+            for name, reqs in by_group.items():
+                g = self.groups[name]
+                fresh = [r for r in reqs if r.cont_ptr is None]
+                if fresh:
+                    with TRACE.span("service.init"):
+                        # init() runs eagerly: pad its batch to the group's slot
+                        # count so every round reuses one compiled shape instead of
+                        # compiling its ops for each new admitted-batch size
+                        queries = np.zeros(g.n_slots, np.int32)
+                        queries[: len(fresh)] = [r.query for r in fresh]
+                        if g.spec.takes_value:
+                            values = np.zeros(g.n_slots, np.int32)
+                            values[: len(fresh)] = [r.value for r in fresh]
+                            ptr0, scr0 = g.spec.iterator.init(
+                                jnp.asarray(queries), jnp.asarray(values),
+                                *g.spec.init_args,
+                            )
+                        else:
+                            ptr0, scr0 = g.spec.iterator.init(
+                                jnp.asarray(queries), *g.spec.init_args
+                            )
+                        TRACE.count("host_syncs", 2)
+                        ptr0 = np.asarray(ptr0, np.int32)
+                        scr0 = np.asarray(scr0, np.int32)
+                free_idx = [i for i, r in enumerate(g.req) if r is None]
+                fi = 0
+                for j, r in enumerate(reqs):
+                    s = free_idx[j]
+                    g.req[s] = r
+                    if r.cont_ptr is None:
+                        g.ptr[s] = ptr0[fi]
+                        g.scratch[s] = scr0[fi]
+                        g.iters[s] = 0
+                        fi += 1
+                    else:  # preempted continuation: resume saved traversal state
+                        g.ptr[s] = r.cont_ptr
+                        g.scratch[s] = r.cont_scratch
+                        g.iters[s] = r.iters
+                        r.cont_ptr = None
+                        r.cont_scratch = None
+                    if r.admit_s < 0:
+                        r.admit_s = now_s
+                        r.admit_round = rnd
 
     def _fast_retire(
         self, g: _SlotGroup, slot: int, status: int, now_s: float, rnd: int
@@ -634,61 +631,63 @@ class PulseService:
     def _drain_emit(self) -> None:
         """Consume retirement events (emit is decoupled from the step loop:
         in async mode this overlaps the device's current quantum)."""
-        m = self.metrics
-        while True:
-            try:
-                writes, r = self._emit.popleft()
-            except IndexError:
-                return
-            m.retired += 1
-            m.writes_retired += int(writes)
-            m.completed += int(r.status == STATUS_DONE)
-            m.faulted += int(r.status == STATUS_FAULT)
-            m.timed_out += int(r.status == STATUS_MAXED)
-            m.retry_exhausted += int(r.status == STATUS_RETRY)
-            m.latencies_ms.append(r.latency_ms)
-            t = m.per_tenant.setdefault(
-                r.tenant, {"completed": 0, "latencies_ms": []}
-            )
-            t["completed"] += int(r.status == STATUS_DONE)
-            t["latencies_ms"].append(r.latency_ms)
-            met = r.deadline_met
-            if met is not None:
-                if met:
-                    m.deadlines_met += 1
-                else:
-                    m.deadlines_missed += 1
+        with TRACE.span("service.emit"):
+            m = self.metrics
+            while True:
+                try:
+                    writes, r = self._emit.popleft()
+                except IndexError:
+                    return
+                m.retired += 1
+                m.writes_retired += int(writes)
+                m.completed += int(r.status == STATUS_DONE)
+                m.faulted += int(r.status == STATUS_FAULT)
+                m.timed_out += int(r.status == STATUS_MAXED)
+                m.retry_exhausted += int(r.status == STATUS_RETRY)
+                m.latencies_ms.append(r.latency_ms)
+                t = m.per_tenant.setdefault(
+                    r.tenant, {"completed": 0, "latencies_ms": []}
+                )
+                t["completed"] += int(r.status == STATUS_DONE)
+                t["latencies_ms"].append(r.latency_ms)
+                met = r.deadline_met
+                if met is not None:
+                    if met:
+                        m.deadlines_met += 1
+                    else:
+                        m.deadlines_missed += 1
 
     def _apply_result(self, g: _SlotGroup, occ, res, dt_s: float, rnd: int) -> None:
-        now_s = time.perf_counter()
-        m = self.metrics
-        m.engine_calls += 1
-        m.quanta_by_path[res.path] = m.quanta_by_path.get(res.path, 0) + 1
-        g.fail_streak = 0  # a quantum landed: the group is healthy again
-        stats = res.stats
-        if stats is not None and hasattr(stats, "supersteps"):
-            m.supersteps += stats.supersteps
-            m.wire_words += stats.total_wire_words
-            m.commits += getattr(stats, "commits", 0)
-        iters_done = 0
-        for s in np.flatnonzero(occ):
-            g.ptr[s] = res.ptr[s]
-            g.scratch[s] = res.scratch[s]
-            lane = int(res.iters[s])
-            g.iters[s] += lane
-            m.lane_iters += lane
-            iters_done = max(iters_done, lane)
-            st = int(res.status[s])
-            if st == STATUS_MAXED and g.iters[s] < self.max_request_iters:
-                continue  # continuation: stays in its slot, resumes next round
-            self._fast_retire(g, int(s), st, now_s, rnd)
-        if iters_done > 0 and dt_s > 0:
-            est = dt_s * 1e3 / iters_done  # ms per iteration, EWMA-smoothed
-            self._ms_per_iter = (
-                est
-                if self._ms_per_iter is None
-                else 0.7 * self._ms_per_iter + 0.3 * est
-            )
+        with TRACE.span("service.apply"):
+            now_s = time.perf_counter()
+            m = self.metrics
+            m.engine_calls += 1
+            m.quanta_by_path[res.path] = m.quanta_by_path.get(res.path, 0) + 1
+            g.fail_streak = 0  # a quantum landed: the group is healthy again
+            stats = res.stats
+            if stats is not None and hasattr(stats, "supersteps"):
+                m.supersteps += stats.supersteps
+                m.wire_words += stats.total_wire_words
+                m.commits += getattr(stats, "commits", 0)
+            iters_done = 0
+            for s in np.flatnonzero(occ):
+                g.ptr[s] = res.ptr[s]
+                g.scratch[s] = res.scratch[s]
+                lane = int(res.iters[s])
+                g.iters[s] += lane
+                m.lane_iters += lane
+                iters_done = max(iters_done, lane)
+                st = int(res.status[s])
+                if st == STATUS_MAXED and g.iters[s] < self.max_request_iters:
+                    continue  # continuation: stays in its slot, resumes next round
+                self._fast_retire(g, int(s), st, now_s, rnd)
+            if iters_done > 0 and dt_s > 0:
+                est = dt_s * 1e3 / iters_done  # ms per iteration, EWMA-smoothed
+                self._ms_per_iter = (
+                    est
+                    if self._ms_per_iter is None
+                    else 0.7 * self._ms_per_iter + 0.3 * est
+                )
 
     def _make_work(self, g: _SlotGroup, rnd: int, quantum: int) -> QuantumWork:
         # NULL pointers in padding (free) slots fault on the first iteration,
@@ -698,55 +697,56 @@ class PulseService:
         rep = self._replicas
 
         def run():
-            t0 = time.perf_counter()
-            p0 = g.ptr.copy()
-            s0 = g.scratch.copy()
-            rep_ctx = None if g.spec.writes else self._replica_ctx()
-            res = self.engine.execute(
-                g.spec.iterator,
-                p0.copy(),
-                s0.copy(),
-                max_iters=quantum,
-                backend=self.backend,
-                compact=self.compact,
-                fused=self.fused,
-                schedule=self.schedule,
-                fabric=self.fabric,
-                replication=rep_ctx,
-            )
-            fanned_out = rep_ctx is not None and bool(
-                np.asarray(rep_ctx.dead_mask).any()
-            )
-            shipped = False
-            if log_writes:
-                # durability point: the quantum is acknowledged once its
-                # *inputs* are in the fsynced log (replaying them through
-                # the commit oracle reproduces the post-commit arena
-                # bit-for-bit); a crash before this line loses only an
-                # unacknowledged quantum.  engine.execute defaults
-                # k_local=4 -- recorded so replay runs the same chase depth.
-                store = self.ft.store
-                seq = store.log_quantum(
-                    g.name, p0, s0,
-                    max_iters=quantum, k_local=4, compact=self.compact,
-                    commits=res.stats.commits, epochs=res.stats.epochs,
+            with TRACE.span("service.quantum", round=rnd):
+                t0 = time.perf_counter()
+                p0 = g.ptr.copy()
+                s0 = g.scratch.copy()
+                rep_ctx = None if g.spec.writes else self._replica_ctx()
+                res = self.engine.execute(
+                    g.spec.iterator,
+                    p0.copy(),
+                    s0.copy(),
+                    max_iters=quantum,
+                    backend=self.backend,
+                    compact=self.compact,
+                    fused=self.fused,
+                    schedule=self.schedule,
+                    fabric=self.fabric,
+                    replication=rep_ctx,
                 )
-                self._writes_since_snapshot += 1
-                if self._writes_since_snapshot >= self.ft.snapshot_every:
-                    store.snapshot(res.arena, seq)
-                    self._writes_since_snapshot = 0
-                if rep is not None:
-                    # ship the quantum's *inputs* to the hot standby: both
-                    # copies apply the same serialized commit stream, so the
-                    # replica is bit-identical to the primary by construction
-                    rep.apply_quantum(
-                        g.spec.iterator, p0, s0,
+                fanned_out = rep_ctx is not None and bool(
+                    np.asarray(rep_ctx.dead_mask).any()
+                )
+                shipped = False
+                if log_writes:
+                    # durability point: the quantum is acknowledged once its
+                    # *inputs* are in the fsynced log (replaying them through
+                    # the commit oracle reproduces the post-commit arena
+                    # bit-for-bit); a crash before this line loses only an
+                    # unacknowledged quantum.  engine.execute defaults
+                    # k_local=4 -- recorded so replay runs the same chase depth.
+                    store = self.ft.store
+                    seq = store.log_quantum(
+                        g.name, p0, s0,
                         max_iters=quantum, k_local=4, compact=self.compact,
+                        commits=res.stats.commits, epochs=res.stats.epochs,
                     )
-                    if self.ft.replication.verify_every_quantum:
-                        rep.verify(res.arena)
-                    shipped = True
-            return res, time.perf_counter() - t0, fanned_out, shipped
+                    self._writes_since_snapshot += 1
+                    if self._writes_since_snapshot >= self.ft.snapshot_every:
+                        store.snapshot(res.arena, seq)
+                        self._writes_since_snapshot = 0
+                    if rep is not None:
+                        # ship the quantum's *inputs* to the hot standby: both
+                        # copies apply the same serialized commit stream, so the
+                        # replica is bit-identical to the primary by construction
+                        rep.apply_quantum(
+                            g.spec.iterator, p0, s0,
+                            max_iters=quantum, k_local=4, compact=self.compact,
+                        )
+                        if self.ft.replication.verify_every_quantum:
+                            rep.verify(res.arena)
+                        shipped = True
+                return res, time.perf_counter() - t0, fanned_out, shipped
 
         def apply(out):
             res, dt_s, fanned_out, shipped = out
@@ -1064,57 +1064,55 @@ class PulseService:
         exactly."""
         m = self.metrics
         rnd = m.rounds if rnd is None else rnd
-        now = time.perf_counter()
-        if self._detector is not None:
-            self._revive_dead_shards(rnd)
-        if self._reshard.phase == "draining":
-            # reshard barrier: arrivals keep queueing, nothing admits, and
-            # the cutover fires the round the last in-flight quantum retires
-            self._intake(now, rnd)
-            if self._reshard.should_cutover(self._in_flight()):
-                self._cutover(rnd)
+        with TRACE.span("service.round", round=rnd):
+            now = time.perf_counter()
+            if self._detector is not None:
+                self._revive_dead_shards(rnd)
+            if self._reshard.phase == "draining":
+                # reshard barrier: arrivals keep queueing, nothing admits, and
+                # the cutover fires the round the last in-flight quantum retires
+                self._intake(now, rnd)
+                if self._reshard.should_cutover(self._in_flight()):
+                    self._cutover(rnd)
+                    self._admit(now, rnd)
+            else:
                 self._admit(now, rnd)
-        else:
-            self._admit(now, rnd)
-        quantum = self._quantum_for_round(now)
-        if m.quantum_min_used == 0 or quantum < m.quantum_min_used:
-            m.quantum_min_used = quantum
-        m.quantum_max_used = max(m.quantum_max_used, quantum)
-        runner = self._ensure_runner()
-        for g in self.groups.values():
-            occupied_before = int(g.occupied().sum())  # count before retirement
-            m.slot_rounds += occupied_before
-            m.capacity_rounds += g.n_slots
-            if occupied_before == 0 or g.backoff_until > rnd:
-                continue  # empty, or parked awaiting a backed-off retry
-            work = self._make_work(g, rnd, quantum)
-            try:
-                if runner is not None:
-                    # a pending runner error surfaces here *before* work
-                    # enqueues: the current group simply re-runs next round
-                    runner.submit(work)
-                else:
-                    work.apply(work.run())
-            except ShardFailure as e:
-                if self.ft is None:
-                    raise
-                if e.label is None:
-                    e.label = g.name
-                self._on_shard_failure(e, rnd)
-        if runner is not None:
-            self._drain_emit()  # overlap: account retirements mid-flight
-            try:
-                runner.drain()  # barrier: slot state settled for next admit
-            except ShardFailure as e:
-                if self.ft is None:
-                    raise
-                self._on_shard_failure(e, rnd)
-        self._drain_emit()
-        if self._watchdog is not None:
-            self._run_watchdog(rnd)
-        if self._detector is not None:
-            self._detector.beat_all(rnd)
-        m.rounds += 1
+            quantum = self._quantum_for_round(now)
+            if m.quantum_min_used == 0 or quantum < m.quantum_min_used:
+                m.quantum_min_used = quantum
+            m.quantum_max_used = max(m.quantum_max_used, quantum)
+            runner = self._ensure_runner()
+            for g in self.groups.values():
+                if not g.occupied().any() or g.backoff_until > rnd:
+                    continue  # empty, or parked awaiting a backed-off retry
+                work = self._make_work(g, rnd, quantum)
+                try:
+                    if runner is not None:
+                        # a pending runner error surfaces here *before* work
+                        # enqueues: the current group simply re-runs next round
+                        runner.submit(work)
+                    else:
+                        work.apply(work.run())
+                except ShardFailure as e:
+                    if self.ft is None:
+                        raise
+                    if e.label is None:
+                        e.label = g.name
+                    self._on_shard_failure(e, rnd)
+            if runner is not None:
+                self._drain_emit()  # overlap: account retirements mid-flight
+                try:
+                    runner.drain()  # barrier: slot state settled for next admit
+                except ShardFailure as e:
+                    if self.ft is None:
+                        raise
+                    self._on_shard_failure(e, rnd)
+            self._drain_emit()
+            if self._watchdog is not None:
+                self._run_watchdog(rnd)
+            if self._detector is not None:
+                self._detector.beat_all(rnd)
+            m.rounds += 1
 
     def close(self) -> None:
         """Stop the background runner (idempotent; restarted on demand)."""
