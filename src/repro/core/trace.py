@@ -24,7 +24,7 @@ Spans the serving path opens:
 
   service.round    ``PulseService.step``, attrs ``round``
   service.admit    ``_admit``: intake, preemption, barriers, admission, slots
-  service.init     inside ``service.admit``: one group's eager ``init``
+  service.init     inside ``service.admit``: one group's compiled ``init``
   service.quantum  one group's quantum (``_make_work``'s ``run``), attrs ``round``
   service.apply    ``_apply_result``: slot scatter and retirement
   service.emit     ``_drain_emit``: retirement accounting
@@ -33,9 +33,11 @@ Spans the serving path opens:
   engine.launch    the local jitted step's enqueue (compiles show here)
   engine.d2h       the blocking reads of the local step's results
 
-and one count, ``host_syncs``: blocking device-to-host reads on the served
-path (the result reads, the eager ``init``'s reads, the ``perms`` scan of
-``routing.can_elide_access_check``).
+and two counts: ``host_syncs``, blocking device-to-host reads on the served
+path (the result reads, the ``perms`` scan of
+``routing.can_elide_access_check``, and an ``init``'s one read where JAX
+exposes no host CPU device); ``host_inits``, fresh batches whose ``init``
+ran on the host CPU device.
 """
 
 from __future__ import annotations

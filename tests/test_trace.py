@@ -126,11 +126,11 @@ def test_read_quantum_holds_its_engine_spans():
             assert records[parent][1] <= t0 and t1 <= records[parent][2], name
 
 
-@pytest.mark.parametrize("kind,idle,admitting", [("traced", 4, 6), ("certified", 5, 7)])
+@pytest.mark.parametrize("kind,idle,admitting", [("traced", 4, 4), ("certified", 5, 5)])
 def test_host_syncs_per_quantum(kind, idle, admitting):
-    """Four result reads a quantum, two reads of an eager ``init``, and one
-    ``perms`` scan where a certificate lets the engine try to elide the
-    access check."""
+    """Four result reads a quantum and one ``perms`` scan where a
+    certificate lets the engine try to elide the access check; a round that
+    admits adds none, since its ``init`` runs on the host CPU device."""
     _, records = _serve(kind)
     per_round: dict[int, int] = {}
     admits: set[int] = set()
@@ -144,6 +144,43 @@ def test_host_syncs_per_quantum(kind, idle, admitting):
     assert admits and set(per_round) - admits
     for rnd, n in per_round.items():
         assert n == (admitting if rnd in admits else idle), (rnd, n)
+
+
+def _init_counts(records):
+    """Per round: (``service.init`` spans, their host_inits, their host_syncs)."""
+    out: dict[int, list[int]] = {}
+    for name, _, _, _, attrs in records:
+        if name == "service.init":
+            c = out.setdefault(attrs["round"], [0, 0, 0])
+            c[0] += 1
+            c[1] += attrs.get("host_inits", 0)
+            c[2] += attrs.get("host_syncs", 0)
+    return out
+
+
+def test_admitting_round_counts_one_host_init_a_fresh_batch():
+    _, records = _serve()
+    counts = _init_counts(records)
+    assert counts
+    for rnd, (batches, host_inits, host_syncs) in counts.items():
+        assert (host_inits, host_syncs) == (batches, 0), rnd
+
+
+def test_init_without_a_host_device_reads_once_on_the_default_device(monkeypatch):
+    """Where JAX exposes no CPU device, the same compiled init runs on the
+    default device: one blocking read a fresh batch, and the same answers."""
+    from repro.serving import traversal_service
+
+    on_host, _ = _serve(trace=False)
+    monkeypatch.setattr(traversal_service, "_host_device", lambda: None)
+    on_default, records = _serve()
+    counts = _init_counts(records)
+    assert counts
+    for rnd, (batches, host_inits, host_syncs) in counts.items():
+        assert (host_inits, host_syncs) == (0, batches), rnd
+    for a, b in zip(on_host, on_default):
+        assert (a.status, a.iters, a.finish_round) == (b.status, b.iters, b.finish_round)
+        np.testing.assert_array_equal(a.result, b.result)
 
 
 def test_async_runner_spans_nest_on_their_thread():
